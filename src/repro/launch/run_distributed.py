@@ -1,7 +1,8 @@
 import os
-os.environ["XLA_FLAGS"] = (os.environ.get("REPRO_XLA_EXTRA", "") +
-                           " --xla_force_host_platform_device_count=" +
-                           os.environ.get("REPRO_DRYRUN_DEVICES", "8")).strip()
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS", ""), os.environ.get("REPRO_XLA_EXTRA", ""),
+    "--xla_force_host_platform_device_count=" +
+    os.environ.get("REPRO_DRYRUN_DEVICES", "8"))))
 
 """ACTUALLY EXECUTE the sharded delay-adaptive train/serve step on a small
 host-device mesh (default 8 CPU devices) -- the dry-run proves lowering; this

@@ -35,6 +35,7 @@ from pathlib import Path
 import jax
 
 from repro import analysis, api, telemetry
+from repro.compile_cache import enable_compile_cache
 from repro.faults import parse_faults
 
 
@@ -168,6 +169,7 @@ def main() -> None:
 
     spec = load_spec(a.spec) if a.spec else spec_from_flags(a)
 
+    enable_compile_cache()
     res = api.run(spec, resume=a.resume)
     grid, n_dev = res.grid, len(jax.devices())
     policy_names = list(dict.fromkeys(c.policy_name for c in grid.cells))

@@ -1,4 +1,4 @@
-"""Asynchronous delay-adaptive training driver (runs on this container).
+"""Asynchronous delay-adaptive training driver.
 
 Implements the paper's parameter-server semantics with REAL stale gradients
 on one host: each simulated worker holds the gradient it computed on the
@@ -31,6 +31,7 @@ from repro.launch.steps import make_trainer
 from repro.models import init_params, loss_fn
 from repro.models.config import ModelConfig
 from repro.checkpoint import save_checkpoint
+from repro.compile_cache import enable_compile_cache
 
 PRESETS = {
     # ~103M params: the end-to-end driver scale
@@ -52,6 +53,25 @@ def make_stream(cfg: ModelConfig, batch: int, seq: int, seed: int = 0):
         return EmbedStream(d_model=cfg.d_model, vocab=cfg.vocab, batch=batch,
                            seq=seq, seed=seed, mrope=cfg.rope == "mrope")
     return TokenStream(vocab=cfg.vocab, batch=batch, seq=seq, seed=seed)
+
+
+def event_trace(n_workers: int, steps: int, *, seed: int = 0,
+                straggler: float = 0.05):
+    """The host event trace ``run_training`` replays: which worker writes
+    at each event and the iterate version it read (``read_at``), from
+    heterogeneous workers with occasional 8x stragglers."""
+    workers = heterogeneous_workers(n_workers, spread=2.0, seed=seed,
+                                    p_straggle=straggler, straggle_x=8.0)
+    return simulate_parameter_server(n_workers, steps, workers, seed=seed)
+
+
+def make_apply(trainer):
+    """The jitted write-event update ``(params, grads, opt, tau) ->
+    (params, opt, gamma)``.  Params and optimizer state are updated in
+    place: without donation a full-width model holds both generations at
+    once (mamba2-780m: 17.2 GB of arguments + outputs against 16 GB on one
+    v5e)."""
+    return jax.jit(trainer.optimizer.step_fn, donate_argnums=(0, 2))
 
 
 def run_training(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
@@ -80,15 +100,13 @@ def run_training(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
     print(f"model={cfg.name} params={n_params/1e6:.1f}M "
           f"policy={policy_name} gamma'={lr} workers={n_workers}")
 
-    workers = heterogeneous_workers(n_workers, spread=2.0, seed=seed,
-                                    p_straggle=straggler, straggle_x=8.0)
-    trace = simulate_parameter_server(n_workers, steps, workers, seed=seed)
+    trace = event_trace(n_workers, steps, seed=seed, straggler=straggler)
     stream = make_stream(cfg, batch, seq, seed)
 
     grad_fn = jax.jit(jax.grad(
         lambda p, b: loss_fn(p, cfg, b)[0]))
     loss_jit = jax.jit(lambda p, b: loss_fn(p, cfg, b)[0])
-    apply_jit = jax.jit(trainer.optimizer.step_fn)
+    apply_jit = make_apply(trainer)
 
     # Algorithm-1 init: every worker computes a gradient at x_0
     pending = {}
@@ -160,8 +178,7 @@ def main() -> None:
         cfg = get_config(args.arch)
         if args.reduced:
             cfg = cfg.reduced()
-        else:
-            print("WARNING: full config on CPU; use --reduced for smoke runs")
+    enable_compile_cache()
     run_training(cfg, steps=args.steps, batch=args.batch, seq=args.seq,
                  policy_name=args.policy, lr=args.lr, n_workers=args.workers,
                  seed=args.seed, out_dir=args.out,

@@ -201,14 +201,21 @@ def make_production_mesh(*, multi_pod: bool = False):
     """Single pod 16x16 ("data","model"); multi-pod 2x16x16 adds "pod"."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Optional[Tuple[str, ...]] = None):
-    """Arbitrary mesh (used by reduced-size tests, e.g. (2, 4))."""
+    """Arbitrary mesh (used by reduced-size tests, e.g. (2, 4)).
+
+    Axes are ``Auto``: the planner places params and batches with
+    ``param_shardings`` / ``batch_shardings`` and leaves the rest to the
+    SPMD partitioner.  (``jax.make_mesh`` defaults to ``Explicit`` axes,
+    under which the embedding gather of a batch-sharded token array from a
+    data-sharded table has no single output sharding.)"""
     if axes is None:
         axes = ("pod", "data", "model")[-len(shape):]
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def dp_axes(mesh) -> Tuple[str, ...]:

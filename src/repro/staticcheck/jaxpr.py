@@ -31,7 +31,7 @@ from typing import Any, Dict, List, Tuple
 
 import jax
 import numpy as np
-from jax.core import ClosedJaxpr, Jaxpr, Literal, Var
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal, Var
 
 __all__ = ["canonical_lines", "canonical_text", "fingerprint", "diff",
            "assert_identical", "io_avals"]

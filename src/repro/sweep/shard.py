@@ -3,7 +3,7 @@
 The batched runners in ``.runners`` collapse a whole grid into one XLA
 program -- but that program lives on ONE device.  This module partitions the
 **cell axis** of a mega-grid across devices with
-``jax.experimental.shard_map`` over a ``("cells",)`` or 2-D
+``jax.shard_map`` over a ``("cells",)`` or 2-D
 ``("cells", "data")`` ``Mesh`` (see :mod:`repro.mesh`):
 
 * the per-cell program is the SAME vmapped cell function the single-device
@@ -45,7 +45,6 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from repro.core.bcd import BCDResult, sample_blocks
@@ -76,9 +75,9 @@ __all__ = ["cell_mesh", "grid_mesh", "mesh_topology", "round_robin_pad",
 
 
 def round_robin_pad(n_cells: int, n_cell_shards: int) -> np.ndarray:
-    """Index map of length ``max(ceil(B / C), 2) * C`` (the 2 only when
-    ``C > 1``) cycling through the B cells, where C is the size of the
-    mesh's **cells axis** -- NOT the total device count.  On a 2-D
+    """Index map of length ``ceil(B / C) * C`` cycling through the B
+    cells, where C is the size of the mesh's **cells axis** -- NOT the
+    total device count.  On a 2-D
     ``(cells, data)`` mesh the data axis replicates the batch, so only the
     cells axis constrains padding; a (2, 4) mesh pads exactly like a (2,)
     mesh.
@@ -86,17 +85,11 @@ def round_robin_pad(n_cells: int, n_cell_shards: int) -> np.ndarray:
     Gathering the stacked inputs through this map pads the batch to a
     cells-axis multiple with REPLAYED cells (not zeros), so every shard
     keeps identical shapes and live work; callers drop rows ``>= n_cells``
-    on the way out.
-
-    Multi-shard cell axes are padded to >= 2 cells per shard: a per-shard
-    batch of exactly 1 makes XLA's sharding propagation reject the
-    ``while``-loop trace scan on jax 0.4 ("tile_assignment should have N
-    devices" on a degenerate ``devices=[0,1]`` sharding), so small grids
-    replay one extra round instead of crashing.
+    on the way out.  One cell per shard is fine.
     """
     if n_cells < 1:
         raise ValueError("empty grid")
-    per_shard = max(-(-n_cells // n_cell_shards), 2 if n_cell_shards > 1 else 1)
+    per_shard = -(-n_cells // n_cell_shards)
     return np.arange(per_shard * n_cell_shards) % n_cells
 
 
@@ -116,14 +109,14 @@ def shard_cells(vmapped_fn: Callable, mesh: Mesh, n_args: int,
     every data shard's output identical -- so ``P("cells")`` out_specs stay
     valid and row values match the 1-D mesh bitwise on integer leaves."""
     specs = tuple(PartitionSpec("cells") for _ in range(n_args))
-    # check_rep=False: jax 0.4's replication checker has no rule for `while`
-    # (the federated client update is a fori_loop with a traced bound); the
-    # body is collective-free on the cells axis and every output is sharded
-    # over it, so the check is vacuous here anyway.  (On 2-D meshes outputs
-    # ARE replicated over "data" -- by the psum argument above -- which the
-    # 0.4 checker could not verify through `while` either.)
-    fn = shard_map(vmapped_fn, mesh=mesh, in_specs=specs,
-                   out_specs=PartitionSpec("cells"), check_rep=False)
+    # check_vma=False: the scan carries start from constants (invariant
+    # over the mesh) and are updated from the per-shard inputs (varying
+    # over "cells"), which the varying-axes checker rejects as a carry
+    # type change.  Every output is sharded over "cells", and on a 2-D
+    # mesh made identical over "data" by pmean_grad's psum, so the
+    # out_specs hold without the check.
+    fn = jax.shard_map(vmapped_fn, mesh=mesh, in_specs=specs,
+                       out_specs=PartitionSpec("cells"), check_vma=False)
     return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
 
@@ -133,25 +126,6 @@ def _pad_gather(tree, idx: np.ndarray):
 
 def _unpad(tree, n: int):
     return jax.tree_util.tree_map(lambda x: x[:n], tree)
-
-
-def _settle_replicas(out, mesh: Mesh):
-    """Reshard 2-D-mesh results onto the 1-D cells submesh (one data
-    column), dropping the data-axis replica copies.
-
-    jax 0.4 sharp edge: a ``check_rep=False`` shard_map output on a
-    ``(cells, data)`` mesh carries ``P("cells")`` sharding, but the SPMD
-    partitioner treats the D identical data-axis copies as PARTIAL SUMS in
-    some downstream multi-operand ops -- ``jnp.concatenate`` of two bucket
-    results returns rows multiplied by exactly D.  ``device_put`` onto a
-    mesh without the data axis materializes one replica and severs the
-    hazard for every consumer (including ``run_bucketed``'s stitch and
-    user code)."""
-    if data_axis_size(mesh) <= 1:
-        return out
-    sub = Mesh(mesh.devices[:, 0], ("cells",))
-    return jax.device_put(out, jax.sharding.NamedSharding(
-        sub, PartitionSpec("cells")))
 
 
 def _run_sharded_bucket(cell_build, mesh: Mesh, args, n_cells: int,
@@ -173,7 +147,7 @@ def _run_sharded_bucket(cell_build, mesh: Mesh, args, n_cells: int,
                data_shards=data_axis_size(mesh),
                cells=int(n_cells)):
         out = fn(*(_pad_gather(a, idx) for a in args))
-    return _unpad(_settle_replicas(out, mesh), n_cells)
+    return _unpad(out, n_cells)
 
 
 # ---------------------------------------------------------------- PIAG ----

@@ -1,27 +1,4 @@
-import sys
-import types
-
 import pytest
-
-try:
-    import hypothesis  # noqa: F401  (real package, if available)
-except ImportError:
-    # Offline container: install the deterministic stub (tests/_hypothesis_stub)
-    # under the `hypothesis` name before test modules import it.
-    from pathlib import Path
-    sys.path.insert(0, str(Path(__file__).parent))
-    import _hypothesis_stub as _stub
-
-    mod = types.ModuleType("hypothesis")
-    mod.given = _stub.given
-    mod.settings = _stub.settings
-    mod.assume = _stub.assume
-    st_mod = types.ModuleType("hypothesis.strategies")
-    for name in ("integers", "floats", "booleans", "sampled_from"):
-        setattr(st_mod, name, getattr(_stub.strategies, name))
-    mod.strategies = st_mod
-    sys.modules["hypothesis"] = mod
-    sys.modules["hypothesis.strategies"] = st_mod
 
 
 def pytest_configure(config):

@@ -11,7 +11,7 @@ Also pinned: the declarative build path (spec -> problem/policies/grid)
 matches the manual construction it automates, spec-build-time horizon
 validation (satellite: fail early instead of the post-hoc ``clipped``
 counter), the legacy shims (DeprecationWarning + bitwise-equal rows), the
-``Results`` common columns, and the small-grid round-robin padding fix.
+``Results`` common columns, and the round-robin padding rule.
 """
 import dataclasses
 import warnings
@@ -356,14 +356,25 @@ def test_execution_spec_bucket_widths_routes_to_runners(problem, prox):
 
 # ------------------------------------------------ shard pad fix ----
 
-def test_round_robin_pad_keeps_two_cells_per_device():
-    """Regression: one cell per device made XLA's sharding propagation
-    reject the while-loop trace scan; small grids now replay a second
-    round-robin round instead."""
-    idx = round_robin_pad(8, 8)
-    assert idx.size == 16 and set(idx) == set(range(8))
-    # single device: no extra padding
+def test_round_robin_pad_one_round_per_cells_axis():
+    """The pad is the next cells-axis multiple and nothing more: one cell
+    per shard is a valid batch, and padding replays real cells in order."""
+    np.testing.assert_array_equal(round_robin_pad(8, 8), np.arange(8))
+    np.testing.assert_array_equal(round_robin_pad(4, 4), np.arange(4))
+    np.testing.assert_array_equal(round_robin_pad(5, 4),
+                                  [0, 1, 2, 3, 4, 0, 1, 2])
     assert round_robin_pad(8, 1).size == 8
-    # big grids unchanged: ceil(12 / 8) is already >= 2 per device
     assert round_robin_pad(12, 8).size == 16
     assert round_robin_pad(512, 8).size == 512
+
+
+def test_execution_devices_beyond_available_raise(problem, worker_grid, prox):
+    """Asking for more devices than exist fails loudly instead of running
+    a smaller mesh."""
+    n = len(jax.devices())
+    spec = api.component_spec("piag", "sharded", problem=problem,
+                              grid=worker_grid, prox=prox)
+    spec = spec.replace(execution=dataclasses.replace(
+        spec.execution, devices=n + 1))
+    with pytest.raises(ValueError, match="devices"):
+        api.run(spec)

@@ -179,11 +179,11 @@ def test_program_cache_keys_distinct_1d_vs_2d(problem):
 # ------------------------------------------------------ round-robin rule ----
 
 def test_round_robin_pad_keys_on_cells_axis():
-    """The >= 2-cells-per-shard rule applies to the cells axis ONLY: 3
-    cells on a (2, 4) mesh pad to 4 rows (2 shards x 2), not to 8 x 2."""
+    """Padding keys on the cells axis ONLY: 3 cells on a (2, 4) mesh pad
+    to 4 rows (2 shards x 2), not to a multiple of all 8 devices."""
     np.testing.assert_array_equal(round_robin_pad(3, 2), [0, 1, 2, 0])
     assert round_robin_pad(3, 2).shape == (4,)
-    # single cell-shard keeps the no-minimum rule regardless of data axis
+    # a single cell-shard never pads, whatever the data axis
     np.testing.assert_array_equal(round_robin_pad(3, 1), [0, 1, 2])
 
 

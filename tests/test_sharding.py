@@ -8,15 +8,8 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 from repro.launch.sharding import (batch_shardings, cache_shardings,
                                    param_shardings)
 
-def _abstract_mesh(sizes, names):
-    try:
-        return AbstractMesh(sizes, names)            # jax >= 0.5
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))  # jax 0.4.x shape_tuple
-
-
-MESH = _abstract_mesh((16, 16), ("data", "model"))
-MESH3 = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def sds(*shape, dtype=jnp.bfloat16):
