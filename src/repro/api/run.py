@@ -49,7 +49,8 @@ from repro.sweep.grid import (SweepGrid, make_grid, measure_tau_bar,
 from repro.telemetry.accumulators import TelemetryConfig, summarize_telemetry
 from repro.telemetry.ledger import (RunRecord, append_record, cache_delta,
                                     estimate_carry_bytes, spec_fingerprint)
-from repro.telemetry.timing import COMPILE_EVENT_NAMES, drain_timings
+from repro.telemetry.timing import (COMPILE_EVENT_NAMES, drain_timings,
+                                    numbered_run, span, timed)
 from repro.sweep.runners import (resolve_grid_horizon, sweep_bcd,
                                  sweep_fedasync, sweep_fedbuff, sweep_piag)
 from repro.mesh import (DATA_AXIS, data_axis_size, grid_mesh,
@@ -610,29 +611,44 @@ def run(spec: ExperimentSpec, resume=None) -> Results:
 
     Every run also builds a ``repro.telemetry.RunRecord`` (surfaced on
     ``Results.telemetry``; appended to the JSONL ledger when one is
-    configured): the timing buffer is drained around the dispatch so
-    compile-side events attribute to THIS run, and the program-cache
-    counters are snapshotted for a reset-scoped hit/miss delta.
+    configured): the timing buffer is drained before the resolve and after
+    the dispatch so this run's events (compile-side ones included)
+    attribute to THIS run, and the program-cache counters are snapshotted
+    for a reset-scoped hit/miss delta.  Its phases are spans numbered by
+    the call (``run``): ``api.resolve`` (with ``api.tau_bar`` inside),
+    ``api.dispatch`` and ``api.record``.
 
     ``resume`` names a checkpoint directory: buckets (batched/sharded) or
     cells (solo) finished by an earlier -- possibly killed -- run of the
     SAME spec are loaded from disk instead of recomputed, and fresh ones
     are persisted there as they complete.  Files are fingerprint-stamped;
     resuming a different spec into the same directory raises."""
-    r = resolve(spec)
-    ckpt = None
-    if resume is not None:
-        ckpt = SweepCheckpoint(
-            resume, spec_fingerprint(spec, r.grid),
-            tag=f"{spec.solver.name}_{spec.execution.backend}")
-    drain_timings()  # drop events from unrelated earlier activity
-    cache_before = program_cache_stats()
-    t0 = time.perf_counter()
-    raw = jax.block_until_ready(_SOLVER_DISPATCH[spec.solver.name](r, ckpt))
-    elapsed = time.perf_counter() - t0
-    record = _build_record(
-        spec, r, raw, elapsed,
-        cache_delta(cache_before, program_cache_stats()), drain_timings())
+    with numbered_run() as n:
+        drain_timings()  # drop events from unrelated earlier activity
+        with timed("api.resolve", run=n):
+            r = resolve(spec)
+        ckpt = None
+        if resume is not None:
+            ckpt = SweepCheckpoint(
+                resume, spec_fingerprint(spec, r.grid),
+                tag=f"{spec.solver.name}_{spec.execution.backend}")
+        cache_before = program_cache_stats()
+        t0 = time.perf_counter()
+        with timed("api.dispatch", run=n):
+            raw = jax.block_until_ready(
+                _SOLVER_DISPATCH[spec.solver.name](r, ckpt))
+        elapsed = time.perf_counter() - t0
+        # the results' device-to-host copies happen here; the span's own
+        # event goes on the record it builds, not into the buffer
+        with span("api.record", run=n):
+            t_rec = time.perf_counter()
+            record = _build_record(
+                spec, r, raw, elapsed,
+                cache_delta(cache_before, program_cache_stats()),
+                drain_timings())
+            record.timings.append(
+                {"name": "api.record",
+                 "ms": (time.perf_counter() - t_rec) * 1e3, "run": n})
     append_record(record)
     return Results(solver=spec.solver.name, backend=spec.execution.backend,
                    grid=r.grid, raw=raw, elapsed_s=elapsed,

@@ -11,7 +11,8 @@ CLI is the human-facing side of that file:
 
 Per run: a delay-histogram sparkline (last bucket = overflow), the
 compile-ms vs warm-ms split and the program-cache delta.  Across runs: a
-solver x backend timing table and the aggregate cache efficiency -- a
+solver x backend timing table, the count and mean ms of each span of the
+records' ``timings`` and the aggregate cache efficiency -- a
 healthy repeated-spec workflow shows compile-ms collapsing to ~0 as the
 program cache warms.
 """
@@ -99,6 +100,20 @@ def render_timing_table(records: List[Dict[str, Any]]) -> List[str]:
     return lines
 
 
+def render_spans(records: List[Dict[str, Any]]) -> List[str]:
+    """Count and mean ``ms`` of each span name in the records' ``timings``
+    (``api.resolve``, ``api.tau_bar``, ``api.dispatch``, ``api.record``,
+    ``sweep.service_times``, ``bucket_dispatch``, ``program_build``...)."""
+    spans: Dict[str, List[float]] = {}
+    for r in records:
+        for ev in r.get("timings", []):
+            spans.setdefault(ev["name"], []).append(float(ev["ms"]))
+    lines = [f"{'span':<24}{'count':>7}{'mean ms':>12}"]
+    for name, ms in sorted(spans.items()):
+        lines.append(f"{name:<24}{len(ms):>7}{sum(ms) / len(ms):>12.3f}")
+    return lines
+
+
 def render_cache(records: List[Dict[str, Any]]) -> str:
     hits = sum(r.get("cache", {}).get("hits", 0) for r in records)
     misses = sum(r.get("cache", {}).get("misses", 0) for r in records)
@@ -119,6 +134,8 @@ def report(records: List[Dict[str, Any]]) -> str:
     out += render_runs(records)
     out += ["", "== solver x backend timing =="]
     out += render_timing_table(records)
+    out += ["", "== spans =="]
+    out += render_spans(records)
     out += ["", render_cache(records)]
     return "\n".join(out)
 

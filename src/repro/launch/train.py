@@ -30,6 +30,7 @@ from repro.data import EmbedStream, TokenStream
 from repro.launch.steps import make_trainer
 from repro.models import init_params, loss_fn
 from repro.models.config import ModelConfig
+from repro import telemetry
 from repro.checkpoint import save_checkpoint
 from repro.compile_cache import enable_compile_cache
 
@@ -74,6 +75,15 @@ def make_apply(trainer):
     return jax.jit(trainer.optimizer.step_fn, donate_argnums=(0, 2))
 
 
+def _memory_counters() -> dict:
+    """``bytes_in_use`` and ``peak_bytes_in_use`` of the default device,
+    where its platform reports them (not on CPU).  Read at a log record,
+    after its fetches have synced the host with the device."""
+    stats = jax.devices()[0].memory_stats() or {}
+    return {k: int(stats[k]) for k in ("bytes_in_use", "peak_bytes_in_use")
+            if k in stats}
+
+
 def run_training(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
                  policy_name: str = "adaptive1", lr: float = 3e-3,
                  n_workers: int = 4, seed: int = 0, log_every: int = 10,
@@ -103,15 +113,23 @@ def run_training(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
     trace = event_trace(n_workers, steps, seed=seed, straggler=straggler)
     stream = make_stream(cfg, batch, seq, seed)
 
-    grad_fn = jax.jit(jax.grad(
-        lambda p, b: loss_fn(p, cfg, b)[0]))
-    loss_jit = jax.jit(lambda p, b: loss_fn(p, cfg, b)[0])
+    # named programs: the XLA modules are jit_train_grad, jit_heldout_loss
+    # and (make_apply) jit_step_fn, which profiler readers key on
+    def train_grad(p, b):
+        return loss_fn(p, cfg, b)[0]
+
+    def heldout_loss(p, b):
+        return loss_fn(p, cfg, b)[0]
+
+    grad_fn = jax.jit(jax.grad(train_grad))
+    loss_jit = jax.jit(heldout_loss)
     apply_jit = make_apply(trainer)
 
     # Algorithm-1 init: every worker computes a gradient at x_0
     pending = {}
-    for w in range(n_workers):
-        pending[w] = (grad_fn(state.params, stream.batch_at(w)), 0)
+    with telemetry.span("train.init_grads", workers=n_workers):
+        for w in range(n_workers):
+            pending[w] = (grad_fn(state.params, stream.batch_at(w)), 0)
 
     params, opt = state.params, state.opt
     log = []
@@ -119,18 +137,27 @@ def run_training(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
     for k in range(steps):
         w = int(trace.worker[k])
         g, s_read = pending[w]
-        tau = jnp.int32(k - s_read)
-        params, opt, gamma = apply_jit(params, g, opt, tau)
-        # worker w picks up x_{k+1} and computes its next gradient
-        pending[w] = (grad_fn(params, stream.batch_at(n_workers + k)), k + 1)
-        if k % log_every == 0 or k == steps - 1:
-            lv = float(loss_jit(params, stream.batch_at(10_000)))
-            rec = {"step": start_step + k, "loss": lv, "gamma": float(gamma),
-                   "tau": int(tau), "wall_s": time.perf_counter() - t0}
-            log.append(rec)
-            print(f"step {start_step + k:5d} loss {lv:.4f} "
-                  f"gamma {float(gamma):.2e} tau {int(tau)} "
-                  f"({rec['wall_s']:.1f}s)")
+        with telemetry.step_span("train.event", start_step + k, worker=w,
+                                 tau=k - s_read):
+            tau = jnp.int32(k - s_read)
+            with telemetry.span("train.apply"):
+                params, opt, gamma = apply_jit(params, g, opt, tau)
+            # worker w picks up x_{k+1} and computes its next gradient
+            with telemetry.span("train.batch"):
+                batch = stream.batch_at(n_workers + k)
+            with telemetry.span("train.grad"):
+                pending[w] = (grad_fn(params, batch), k + 1)
+            if k % log_every == 0 or k == steps - 1:
+                with telemetry.span("train.log"):
+                    lv = float(loss_jit(params, stream.batch_at(10_000)))
+                    rec = {"step": start_step + k, "loss": lv,
+                           "gamma": float(gamma), "tau": int(tau),
+                           "wall_s": time.perf_counter() - t0,
+                           **_memory_counters()}
+                    log.append(rec)
+                    print(f"step {start_step + k:5d} loss {lv:.4f} "
+                          f"gamma {float(gamma):.2e} tau {int(tau)} "
+                          f"({rec['wall_s']:.1f}s)")
         if out_dir and save_every and (k + 1) % save_every == 0:
             os.makedirs(out_dir, exist_ok=True)
             from repro.launch.steps import TrainState

@@ -43,14 +43,13 @@ from __future__ import annotations
 
 import hashlib
 import os
-import time
 from collections import OrderedDict
 from typing import Any, Callable, Optional, Tuple
 
 import jax
 import numpy as np
 
-from repro.telemetry.timing import record_timing
+from repro.telemetry.timing import timed
 
 __all__ = ["IdKey", "LRU", "tree_key", "cached_program",
            "clear_program_cache", "mesh_fingerprint", "program_cache_stats",
@@ -244,11 +243,8 @@ class _TimedFirstCall:
         if not self.pending:
             return self.fn(*args, **kwargs)
         self.pending = False
-        t0 = time.perf_counter()
-        out = self.fn(*args, **kwargs)
-        record_timing("program_first_call",
-                      (time.perf_counter() - t0) * 1e3, key=self.tag)
-        return out
+        with timed("program_first_call", key=self.tag):
+            return self.fn(*args, **kwargs)
 
 
 def cached_program(key: Tuple, build: Callable[[], Any]):
@@ -266,10 +262,8 @@ def cached_program(key: Tuple, build: Callable[[], Any]):
 
     def timed_build():
         tag = str(key[0]) if key else "?"
-        t0 = time.perf_counter()
-        val = build()
-        record_timing("program_build", (time.perf_counter() - t0) * 1e3,
-                      key=tag)
+        with timed("program_build", key=tag):
+            val = build()
         return _TimedFirstCall(val, tag) if callable(val) else val
 
     return _PROGRAMS.get(key, timed_build)

@@ -42,6 +42,7 @@ import numpy as np
 from repro.core.engine import (WorkerModel, heterogeneous_workers,
                                sample_service_times, trace_scan)
 from repro.core.stepsize import StepsizePolicy, next_pow2
+from repro.telemetry.timing import run_number, timed
 
 from .policies import PolicyParams, stack_params
 
@@ -49,10 +50,34 @@ __all__ = ["SweepCell", "SweepGrid", "SweepBucket", "make_grid",
            "measure_tau_bar", "next_pow2", "standard_topologies",
            "standard_topology_factories"]
 
+
+def _measure_program():
+    # nested: its name, which the XLA module takes, is that of the public
+    # measure_tau_bar below
+    def measure_tau_bar(T):
+        return trace_scan(T).tau_max
+    return jax.jit(jax.vmap(measure_tau_bar))
+
+
 # one jitted trace-delay program for every tau-bar measurement in the repo
 # (module-level so repeated resolves/builds reuse the trace instead of
-# re-tracing an anonymous jit each call; jax re-specializes per shape)
-_tau_max_jit = jax.jit(jax.vmap(lambda T: trace_scan(T).tau_max))
+# re-tracing each call; jax re-specializes per shape).  Its XLA module is
+# jit_measure_tau_bar, the name profiler readers key on.
+_tau_max_jit = _measure_program()
+
+
+def _worst_delay(groups, n_events: int) -> int:
+    """The largest trace delay over ``groups`` of (workers, seed) pairs,
+    each group of one worker count: the host draws the service times, one
+    vmapped program per group scans them.  One ``api.tau_bar`` span."""
+    with timed("api.tau_bar", run=run_number()):
+        worst = 0
+        for pairs in groups:
+            Ts = np.stack([sample_service_times(ws, n_events + 1, seed=int(s))
+                           for ws, s in pairs])
+            taus = _tau_max_jit(jnp.asarray(Ts))
+            worst = max(worst, int(np.max(np.asarray(taus))))
+        return worst
 
 
 @dataclasses.dataclass(frozen=True)
@@ -113,14 +138,8 @@ class SweepGrid:
             seen.setdefault((c.topology_name, c.seed), c)
         by_width: Dict[int, list] = {}
         for c in seen.values():
-            by_width.setdefault(c.n_workers, []).append(c)
-        worst = 0
-        for cs in by_width.values():
-            Ts = np.stack([sample_service_times(c.workers, self.n_events + 1,
-                                                seed=c.seed) for c in cs])
-            taus = _tau_max_jit(jnp.asarray(Ts))
-            worst = max(worst, int(np.max(np.asarray(taus))))
-        return worst
+            by_width.setdefault(c.n_workers, []).append((c.workers, c.seed))
+        return _worst_delay(by_width.values(), self.n_events)
 
     @property
     def is_ragged(self) -> bool:
@@ -250,15 +269,8 @@ def measure_tau_bar(topologies: Dict[str, Sequence], seeds: Sequence[int],
     """
     by_width: Dict[int, list] = {}
     for ws in topologies.values():
-        by_width.setdefault(len(ws), []).append(ws)
-    worst = 0
-    for groups in by_width.values():
-        Ts = np.stack([
-            sample_service_times(ws, n_events + 1, seed=int(s))
-            for ws in groups for s in seeds])
-        taus = _tau_max_jit(jnp.asarray(Ts))
-        worst = max(worst, int(np.max(np.asarray(taus))))
-    return worst
+        by_width.setdefault(len(ws), []).extend((ws, s) for s in seeds)
+    return _worst_delay(by_width.values(), n_events)
 
 
 def make_grid(policies: Dict[str, StepsizePolicy],

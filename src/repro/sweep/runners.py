@@ -49,7 +49,7 @@ from repro.faults.spec import normalize_faults
 from repro.faults.inject import (inject_client_rounds, inject_service_times,
                                  update_fault_codes)
 
-from repro.telemetry.timing import timed
+from repro.telemetry.timing import run_number, timed
 
 from .cache import IdKey, LRU, cached_program, tree_key
 from .grid import SweepBucket, SweepGrid
@@ -168,6 +168,12 @@ def _slice_workers(worker_data, width: int):
 
 # ---------------------------------------------------------------- PIAG ----
 
+def _service_times(b: SweepBucket) -> jnp.ndarray:
+    """The bucket's (B, width, K+1) service times, drawn on the host."""
+    with timed("sweep.service_times", run=run_number(), cells=len(b.index)):
+        return jnp.asarray(b.grid.service_times(b.width))
+
+
 def _cell_seeds(b: SweepBucket) -> jnp.ndarray:
     """(B,) per-cell seeds -- the traced argument keying the fault streams
     (fold_in inside the jit, so solo/batched/sharded rows stay bitwise)."""
@@ -277,7 +283,7 @@ def sweep_piag(worker_loss: Callable, x0, worker_data, grid: SweepGrid,
             masked=not b.uniform, record_every=record_every,
             donate=_donate_default(), telemetry=telemetry, engine=engine,
             faults=faults))
-        T = jnp.asarray(b.grid.service_times(b.width))
+        T = _service_times(b)
         pp = b.grid.policy_params()
         tail = (_cell_seeds(b),) if faults is not None else ()
         if b.uniform:
@@ -381,7 +387,7 @@ def sweep_bcd(grad_f: Callable, objective: Callable, x0, m: int,
             masked=not b.uniform, record_every=record_every,
             donate=_donate_default(), telemetry=telemetry, engine=engine,
             faults=faults))
-        T = jnp.asarray(b.grid.service_times(b.width))
+        T = _service_times(b)
         blocks = jnp.asarray(np.stack([
             sample_blocks(m, grid.n_events, seed=c.seed)
             for c in b.grid.cells]))
@@ -419,12 +425,13 @@ def _stack_fed_rounds(grid: SweepGrid, width: int, n_steps: int):
     p_drop = np.zeros((B, width), np.float32)
     rejoin = np.ones((B, width), np.float32)
     epochs = np.ones((B, width), np.int32)
-    for i, c in enumerate(grid.cells):
-        n = c.n_workers
-        r = sample_client_rounds(list(c.workers), n_steps, seed=c.seed)
-        drop_u[i, :n], dur[i, :n] = r.drop_u, r.duration
-        p_drop[i, :n], rejoin[i, :n], epochs[i, :n] = client_arrays(
-            list(c.workers))
+    with timed("sweep.service_times", run=run_number(), cells=B):
+        for i, c in enumerate(grid.cells):
+            n = c.n_workers
+            r = sample_client_rounds(list(c.workers), n_steps, seed=c.seed)
+            drop_u[i, :n], dur[i, :n] = r.drop_u, r.duration
+            p_drop[i, :n], rejoin[i, :n], epochs[i, :n] = client_arrays(
+                list(c.workers))
     rounds = ClientRounds(jnp.asarray(drop_u), jnp.asarray(dur))
     cparams = (jnp.asarray(p_drop), jnp.asarray(rejoin), jnp.asarray(epochs))
     return rounds, cparams, jnp.asarray(grid.active_masks(width))

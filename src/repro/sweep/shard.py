@@ -63,8 +63,9 @@ from .cache import IdKey, cached_program, tree_key
 from .grid import SweepBucket, SweepGrid
 from .runners import (Horizon, _bcd_cell, _cell_seeds, _fed_cell,
                       _fedasync_scan_adapter, _fedbuff_scan_adapter,
-                      _piag_cell, _slice_workers, _stack_fed_rounds,
-                      _check_fed_diag, resolve_grid_horizon, run_bucketed)
+                      _piag_cell, _service_times, _slice_workers,
+                      _stack_fed_rounds, _check_fed_diag,
+                      resolve_grid_horizon, run_bucketed)
 
 __all__ = ["cell_mesh", "grid_mesh", "mesh_topology", "round_robin_pad",
            "shard_cells",
@@ -203,7 +204,7 @@ def sharded_sweep_piag(worker_loss: Callable, x0, worker_data,
                record_every, telemetry, engine, faults, mesh_topology(mesh),
                IdKey(worker_loss), tree_key(x0), tree_key(worker_data),
                IdKey(prox), IdKey(objective))
-        T = jnp.asarray(b.grid.service_times(b.width))
+        T = _service_times(b)
         pp = b.grid.policy_params()
         args = ((T, pp) if b.uniform else
                 (T, jnp.asarray(b.grid.active_masks(b.width)), pp))
@@ -298,7 +299,7 @@ def sharded_sweep_bcd(grad_f: Callable, objective: Callable, x0, m: int,
                record_every, telemetry, engine, faults, mesh_topology(mesh),
                IdKey(gf),
                IdKey(objective), tree_key(x0), IdKey(prox))
-        T = jnp.asarray(b.grid.service_times(b.width))
+        T = _service_times(b)
         blocks = jnp.asarray(np.stack([
             sample_blocks(m, grid.n_events, seed=c.seed)
             for c in b.grid.cells]))
