@@ -73,6 +73,7 @@ def piag_scan(
     faults: FaultSpec | None = None,
     fault_codes: jnp.ndarray | None = None,
     grad_fn: Callable | None = None,  # (x, *worker_data_slice) -> grad pytree
+    grad_layout: str = "gathered",
 ) -> PIAGResult:
     """The traceable PIAG core: Algorithm 1 as a pure ``lax.scan``.
 
@@ -122,9 +123,25 @@ def piag_scan(
     rides the carry onto ``result.faults``.  ``faults=None`` is bitwise the
     pre-fault jaxpr -- the guarded body is a SEPARATE code path, not a
     predicated version of the old one.
+
+    ``grad_layout`` is how the returning worker's gradient reads the worker
+    data.  ``'gathered'`` slices worker w's shard and differentiates on it;
+    under a vmap over cells that slice is a gather that copies one shard
+    per cell every event.  ``'grouped'`` differentiates every worker's loss
+    at the returning worker's snapshot and keeps row w: under the cells
+    vmap that is two products over the stacked shards, each reading the
+    data once per event whatever the number of cells; they run at the
+    highest matmul precision, the float32 arithmetic that the gathered
+    layout's matrix-vector products get.  Rows agree to rounding; padded
+    (masked) workers' gradients are computed and never selected.  The batched runners choose the layout
+    from the cell count and width
+    (``repro.sweep.runners.pick_grad_layout``).
     """
     if engine not in ("scan", "fused"):
         raise ValueError(f"engine must be 'scan' or 'fused', got {engine!r}")
+    if grad_layout not in ("gathered", "grouped"):
+        raise ValueError("grad_layout must be 'gathered' or 'grouped', got "
+                         f"{grad_layout!r}")
     faults = normalize_faults(faults)
     if faults is not None:
         if engine == "fused":
@@ -160,6 +177,21 @@ def piag_scan(
     def data_at(w):
         return jax.tree_util.tree_map(lambda leaf: leaf[w], worker_data)
 
+    def worker_grad(x_read, w):
+        """grad f_w(x_read[w]): the returning worker's gradient (Algorithm
+        1 line 12) in the chosen ``grad_layout``."""
+        xw = jax.tree_util.tree_map(lambda leaf: leaf[w], x_read)
+        if grad_layout == "gathered":
+            return grad_i(xw, *jax.tree_util.tree_leaves(data_at(w)))
+        # the gathered layout's per-cell matrix-vector products compile to
+        # float32 multiply-reduces; these batched products go to the matrix
+        # unit, which at the default precision rounds its operands to
+        # bfloat16 on the TPU.  'highest' keeps float32 arithmetic.
+        with jax.default_matmul_precision("highest"):
+            every = jax.vmap(lambda *d: grad_i(xw, *d))(
+                *jax.tree_util.tree_leaves(worker_data))
+        return jax.tree_util.tree_map(lambda g: g[w], every)
+
     if objective is None:
         def objective(x):
             losses = jax.vmap(lambda i: worker_loss(x, *jax.tree_util.tree_leaves(data_at(i))))
@@ -181,9 +213,7 @@ def piag_scan(
         def step(carry, event):
             x, gtab, x_read, ss = carry[:4]
             w, tau = event
-            # worker w returns grad f_w(x_read[w])  (Algorithm 1 line 12)
-            xw = jax.tree_util.tree_map(lambda leaf: leaf[w], x_read)
-            gw = grad_i(xw, *jax.tree_util.tree_leaves(data_at(w)))
+            gw = worker_grad(x_read, w)
             gtab = jax.tree_util.tree_map(lambda buf, gnew: buf.at[w].set(gnew), gtab, gw)
             # line 14: aggregate; line 16: delay-adaptive gamma; line 17: prox step
             # g is materialized before the update under either engine: the
@@ -233,8 +263,7 @@ def piag_scan(
             x, gtab, x_read, ss = carry[:4]
             fs = carry[fi]
             w, tau, code = event
-            xw = jax.tree_util.tree_map(lambda leaf: leaf[w], x_read)
-            gw = grad_i(xw, *jax.tree_util.tree_leaves(data_at(w)))
+            gw = worker_grad(x_read, w)
             # update-level corruption: poison the payload BEFORE the guard
             gw = jax.tree_util.tree_map(
                 lambda a: (a + jnp.where(code == CODE_CORRUPT, poison,

@@ -50,8 +50,8 @@ from .policies import POLICY_IDS, ParamPolicy, PolicyParams, policy_params, stac
 from .runners import (make_sweep_bcd, make_sweep_fedasync,
                       make_sweep_fedasync_fused, make_sweep_fedbuff,
                       make_sweep_piag, measure_fed_tau_bar,
-                      resolve_grid_horizon, run_bucketed, sweep_bcd,
-                      sweep_bcd_logreg, sweep_fedasync,
+                      pick_grad_layout, resolve_grid_horizon, run_bucketed,
+                      sweep_bcd, sweep_bcd_logreg, sweep_fedasync,
                       sweep_fedasync_problem, sweep_fedbuff,
                       sweep_fedbuff_problem, sweep_piag, sweep_piag_logreg)
 from .shard import (cell_mesh, grid_mesh, make_sharded_sweep_bcd,
@@ -64,7 +64,7 @@ __all__ = [
     "SweepBucket", "SweepCell", "SweepGrid", "make_grid", "measure_tau_bar",
     "next_pow2", "standard_topologies", "standard_topology_factories",
     "clear_program_cache", "program_cache_stats", "measure_fed_tau_bar",
-    "resolve_grid_horizon",
+    "resolve_grid_horizon", "pick_grad_layout",
     "POLICY_IDS", "ParamPolicy", "PolicyParams", "policy_params",
     "stack_params", "make_sweep_bcd", "make_sweep_fedasync",
     "make_sweep_fedasync_fused", "make_sweep_fedbuff", "make_sweep_piag",

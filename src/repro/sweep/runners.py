@@ -59,7 +59,8 @@ __all__ = ["make_sweep_piag", "sweep_piag", "sweep_piag_logreg",
            "make_sweep_bcd", "sweep_bcd", "sweep_bcd_logreg",
            "make_sweep_fedasync", "sweep_fedasync", "sweep_fedasync_problem",
            "make_sweep_fedbuff", "sweep_fedbuff", "sweep_fedbuff_problem",
-           "run_bucketed", "resolve_grid_horizon", "measure_fed_tau_bar"]
+           "run_bucketed", "resolve_grid_horizon", "measure_fed_tau_bar",
+           "pick_grad_layout"]
 
 Horizon = Union[int, str]  # a concrete H or "auto" (measured-delay sizing)
 
@@ -119,7 +120,7 @@ def _warn_legacy(name: str) -> None:
 
 def run_bucketed(grid: SweepGrid, run_bucket: Callable,
                  bucket_widths: Optional[Sequence[int]] = None,
-                 checkpoint=None):
+                 checkpoint=None, span_meta: Optional[Callable] = None):
     """Run ``run_bucket(bucket) -> result (leading B_bucket)`` over every
     bucket of ``grid`` and stitch rows back into grid cell order.  Shared by
     the single-device runners here and the sharded runners in ``.shard``.
@@ -129,7 +130,9 @@ def run_bucketed(grid: SweepGrid, run_bucket: Callable,
     instead of run, and each freshly-computed bucket is persisted (with a
     device sync first -- a checkpoint must never record an enqueued-but-
     unfinished computation) before the next one starts, so a killed
-    mega-grid sweep resumes at its first unfinished bucket."""
+    mega-grid sweep resumes at its first unfinished bucket.
+    ``span_meta(bucket) -> dict`` adds the runner's own fields to each
+    bucket's ``bucket_dispatch`` span."""
     buckets = grid.buckets(bucket_widths)
     parts = []
     for i, b in enumerate(buckets):
@@ -140,7 +143,9 @@ def run_bucketed(grid: SweepGrid, run_bucket: Callable,
                 continue
         # telemetry: per-bucket dispatch wall time (build + trace + enqueue;
         # execution may still be async -- api.run's block covers that)
-        with timed("bucket_dispatch", width=b.width, cells=len(b.index)):
+        meta = {} if span_meta is None else span_meta(b)
+        with timed("bucket_dispatch", width=b.width, cells=len(b.index),
+                   **meta):
             part = run_bucket(b)
         if checkpoint is not None:
             part = jax.block_until_ready(part)
@@ -180,9 +185,27 @@ def _cell_seeds(b: SweepBucket) -> jnp.ndarray:
     return jnp.asarray([c.seed for c in b.grid.cells], jnp.int32)
 
 
+def pick_grad_layout(n_cells: int, width: int,
+                     grad_fn: Optional[Callable] = None) -> str:
+    """The worker-gradient layout (``core.piag.piag_scan``'s
+    ``grad_layout``) of a batched PIAG program whose one device runs
+    ``n_cells`` cells of ``width`` workers each.
+
+    Per event, gathered reads and writes a copy of one shard per cell and
+    reads the copy twice (4 shard passes a cell); grouped reads all
+    ``width`` shards twice.  Their bytes meet at half the width, where the
+    chip still measured gathered ahead; so grouped is taken above half the
+    width.  An injected ``grad_fn`` (the 2-D mesh's ``pmean_grad``) keeps
+    gathered: its psum is written for one worker's slice."""
+    if grad_fn is None and 2 * n_cells > width:
+        return "grouped"
+    return "gathered"
+
+
 def _piag_cell(worker_loss, x0, worker_data, prox, objective, horizon,
                use_tau_max, masked, record_every=1, telemetry=None,
-               engine="scan", faults=None, grad_fn=None):
+               engine="scan", faults=None, grad_fn=None,
+               grad_layout="gathered"):
     """The per-cell program (trace generation fused with the solver scan);
     ``jax.vmap`` of this is the batched program, ``shard_map(vmap(...))``
     the sharded one.  With ``faults`` the cell signature grows a trailing
@@ -190,7 +213,8 @@ def _piag_cell(worker_loss, x0, worker_data, prox, objective, horizon,
     the trace scan and the per-event codes drawn from the same seed, all
     inside the one executable.  ``grad_fn`` is the 2-D mesh seam: the
     sharded runner injects ``pmean_grad`` so worker gradients psum over the
-    mesh's data axis (None everywhere else -- off-is-absent)."""
+    mesh's data axis (None everywhere else -- off-is-absent).
+    ``grad_layout`` is ``piag_scan``'s (see ``pick_grad_layout``)."""
     if faults is not None:
         def faulted(T, active, pp, seed):
             T = inject_service_times(T, faults, seed)
@@ -203,7 +227,7 @@ def _piag_cell(worker_loss, x0, worker_data, prox, objective, horizon,
                              horizon=horizon, active=active,
                              record_every=record_every, telemetry=telemetry,
                              engine=engine, faults=faults, fault_codes=codes,
-                             grad_fn=grad_fn)
+                             grad_fn=grad_fn, grad_layout=grad_layout)
         if masked:
             return lambda T, active, pp, seed: faulted(T, active, pp, seed)
         return lambda T, pp, seed: faulted(T, None, pp, seed)
@@ -215,7 +239,8 @@ def _piag_cell(worker_loss, x0, worker_data, prox, objective, horizon,
                              ParamPolicy(pp), prox, objective=objective,
                              horizon=horizon, active=active,
                              record_every=record_every, telemetry=telemetry,
-                             engine=engine, grad_fn=grad_fn)
+                             engine=engine, grad_fn=grad_fn,
+                             grad_layout=grad_layout)
     else:
         def cell(T, pp):
             tr = trace_scan(T)
@@ -224,7 +249,7 @@ def _piag_cell(worker_loss, x0, worker_data, prox, objective, horizon,
                              ParamPolicy(pp), prox, objective=objective,
                              horizon=horizon, record_every=record_every,
                              telemetry=telemetry, engine=engine,
-                             grad_fn=grad_fn)
+                             grad_fn=grad_fn, grad_layout=grad_layout)
     return cell
 
 
@@ -233,7 +258,7 @@ def make_sweep_piag(worker_loss: Callable, x0, worker_data, prox: ProxOp,
                     use_tau_max: bool = True, masked: bool = False,
                     record_every: int = 1, donate: bool = False,
                     telemetry=None, engine: str = "scan",
-                    faults=None) -> Callable:
+                    faults=None, grad_layout: str = "gathered") -> Callable:
     """Build the batched PIAG program.
 
     Returns jitted ``fn(service_times (B, n, K+1), params (B,)) ->
@@ -243,10 +268,13 @@ def make_sweep_piag(worker_loss: Callable, x0, worker_data, prox: ProxOp,
     tensor (arg 0) so its buffer is reused in place -- pass a fresh array
     per call (the ``sweep_*`` runners do).  ``engine='fused'`` selects the
     Pallas fused per-event kernel inside the scan core (bitwise-equal).
+    ``grad_layout`` is the worker-gradient layout; ``sweep_piag`` picks it
+    per bucket with ``pick_grad_layout``.
     """
     return jax.jit(jax.vmap(_piag_cell(
         worker_loss, x0, worker_data, prox, objective, horizon, use_tau_max,
-        masked, record_every, telemetry, engine, normalize_faults(faults))),
+        masked, record_every, telemetry, engine, normalize_faults(faults),
+        grad_layout=grad_layout)),
         donate_argnums=(0,) if donate else ())
 
 
@@ -268,21 +296,27 @@ def sweep_piag(worker_loss: Callable, x0, worker_data, grid: SweepGrid,
     buffer from the grid's measured tau-bar (``resolve_grid_horizon``).
     ``faults`` (a ``FaultSpec``) rides the cache key and switches the cell
     program to the fault-injected form (extra per-cell seed argument);
-    ``checkpoint`` makes the bucket loop resumable (``run_bucketed``)."""
+    ``checkpoint`` makes the bucket loop resumable (``run_bucketed``).
+    Each bucket's worker-gradient layout follows its cell count and width
+    (``pick_grad_layout``) and rides the cache key."""
     horizon = resolve_grid_horizon(horizon, grid)
     faults = normalize_faults(faults)
 
+    def layout_of(b: SweepBucket) -> str:
+        return pick_grad_layout(len(b.index), b.width)
+
     def run_bucket(b: SweepBucket):
+        layout = layout_of(b)
         key = ("piag", b.width, not b.uniform, horizon, use_tau_max,
-               record_every, telemetry, engine, faults, IdKey(worker_loss),
-               tree_key(x0), tree_key(worker_data), IdKey(prox),
-               IdKey(objective))
+               record_every, telemetry, engine, faults, layout,
+               IdKey(worker_loss), tree_key(x0), tree_key(worker_data),
+               IdKey(prox), IdKey(objective))
         fn = cached_program(key, lambda: make_sweep_piag(
             worker_loss, x0, _slice_workers(worker_data, b.width), prox,
             objective=objective, horizon=horizon, use_tau_max=use_tau_max,
             masked=not b.uniform, record_every=record_every,
             donate=_donate_default(), telemetry=telemetry, engine=engine,
-            faults=faults))
+            faults=faults, grad_layout=layout))
         T = _service_times(b)
         pp = b.grid.policy_params()
         tail = (_cell_seeds(b),) if faults is not None else ()
@@ -291,7 +325,8 @@ def sweep_piag(worker_loss: Callable, x0, worker_data, grid: SweepGrid,
         return fn(T, jnp.asarray(b.grid.active_masks(b.width)), pp, *tail)
 
     return run_bucketed(grid, run_bucket, bucket_widths,
-                        checkpoint=checkpoint)
+                        checkpoint=checkpoint,
+                        span_meta=lambda b: {"grad": layout_of(b)})
 
 
 def sweep_piag_logreg(problem, grid: SweepGrid, prox: ProxOp,

@@ -64,7 +64,7 @@ from .grid import SweepBucket, SweepGrid
 from .runners import (Horizon, _bcd_cell, _cell_seeds, _fed_cell,
                       _fedasync_scan_adapter, _fedbuff_scan_adapter,
                       _piag_cell, _service_times, _slice_workers,
-                      _stack_fed_rounds, _check_fed_diag,
+                      _stack_fed_rounds, _check_fed_diag, pick_grad_layout,
                       resolve_grid_horizon, run_bucketed)
 
 __all__ = ["cell_mesh", "grid_mesh", "mesh_topology", "round_robin_pad",
@@ -159,6 +159,13 @@ def _dp_grad_for(worker_loss: Callable, mesh: Mesh) -> Optional[Callable]:
     return pmean_grad(worker_loss, DATA_AXIS, D) if D > 1 else None
 
 
+def _piag_grad_layout(n_cells: int, width: int, mesh: Mesh,
+                      grad_fn: Optional[Callable]) -> str:
+    """``pick_grad_layout`` for the cells one device of ``mesh`` runs."""
+    return pick_grad_layout(-(-n_cells // cell_axis_size(mesh)), width,
+                            grad_fn)
+
+
 def make_sharded_sweep_piag(worker_loss: Callable, x0, worker_data,
                             prox: ProxOp, objective: Optional[Callable] = None,
                             horizon: int = 4096, use_tau_max: bool = True,
@@ -193,17 +200,22 @@ def sharded_sweep_piag(worker_loss: Callable, x0, worker_data,
     """``sweep_piag`` with the cell axis sharded across the mesh's cells
     axis; a 2-D ``(cells, data)`` mesh adds data-parallel worker gradients
     (``pmean_grad`` psums over "data"; rows stay bitwise on integer
-    leaves)."""
+    leaves).  Each bucket's worker-gradient layout follows the cells one
+    device runs (``pick_grad_layout``) and rides the cache key."""
     mesh = cell_mesh() if mesh is None else mesh
     horizon = resolve_grid_horizon(horizon, grid)
     faults = normalize_faults(faults)
     grad_fn = _dp_grad_for(worker_loss, mesh)
 
+    def layout_of(b: SweepBucket) -> str:
+        return _piag_grad_layout(len(b.grid), b.width, mesh, grad_fn)
+
     def run_bucket(b: SweepBucket):
+        layout = layout_of(b)
         key = ("piag/sharded", b.width, not b.uniform, horizon, use_tau_max,
-               record_every, telemetry, engine, faults, mesh_topology(mesh),
-               IdKey(worker_loss), tree_key(x0), tree_key(worker_data),
-               IdKey(prox), IdKey(objective))
+               record_every, telemetry, engine, faults, layout,
+               mesh_topology(mesh), IdKey(worker_loss), tree_key(x0),
+               tree_key(worker_data), IdKey(prox), IdKey(objective))
         T = _service_times(b)
         pp = b.grid.policy_params()
         args = ((T, pp) if b.uniform else
@@ -215,11 +227,13 @@ def sharded_sweep_piag(worker_loss: Callable, x0, worker_data,
                                _slice_workers(worker_data, b.width), prox,
                                objective, horizon, use_tau_max,
                                not b.uniform, record_every, telemetry,
-                               engine, faults, grad_fn=grad_fn),
+                               engine, faults, grad_fn=grad_fn,
+                               grad_layout=layout),
             mesh, args, len(b.grid), n_args=len(args), cache_key=key)
 
     return run_bucketed(grid, run_bucket, bucket_widths,
-                        checkpoint=checkpoint)
+                        checkpoint=checkpoint,
+                        span_meta=lambda b: {"grad": layout_of(b)})
 
 
 def sharded_sweep_piag_logreg(problem, grid: SweepGrid, prox: ProxOp,

@@ -160,16 +160,15 @@ def test_tau_bar_program_name():
 
 # ------------------------------------------------------------- api.run ----
 
-def declarative_spec(seeds=(0, 1)):
+def declarative_spec(seeds=(0, 1), names=("uniform", "straggler"),
+                     policies=("adaptive1", "fixed")):
     return api.ExperimentSpec(
         problem=api.ProblemSpec(kind="logreg",
                                 params=dict(n_samples=120, dim=20, seed=0)),
         solver=api.SolverSpec(name="piag", horizon=4096),
-        topology=api.TopologySpec(kind="standard",
-                                  names=("uniform", "straggler"),
+        topology=api.TopologySpec(kind="standard", names=names,
                                   n_workers=(4,)),
-        policies=api.PolicyGridSpec(names=("adaptive1", "fixed"),
-                                    seeds=seeds),
+        policies=api.PolicyGridSpec(names=policies, seeds=seeds),
         n_events=60)
 
 
@@ -198,6 +197,24 @@ def test_run_record_holds_its_phases(tmp_path):
     assert resolve[1] + resolve[2] <= dispatch[1]
     assert dispatch[1] + dispatch[2] <= record[1]
     assert {e[3]["run"] for e in (resolve, tau_bar, dispatch, record)} == {n}
+    assert drain_timings() == []
+
+
+@pytest.mark.parametrize("cells,layout", [(1, "gathered"),
+                                          (4, "grouped")])
+def test_bucket_dispatch_carries_grad_layout(tmp_path, cells, layout):
+    """Width 4: one cell keeps the gathered worker gradient, four take the
+    grouped one; the bucket's span and its buffered event say which."""
+    spec = (declarative_spec(seeds=(0,), names=("uniform",),
+                             policies=("adaptive1",)) if cells == 1
+            else declarative_spec(seeds=(0,)))
+    res, events = capture(tmp_path, lambda: api.run(spec))
+    assert len(res) == cells
+    (buffered,) = [t for t in res.telemetry.timings
+                   if t["name"] == "bucket_dispatch"]
+    assert buffered["grad"] == layout and buffered["cells"] == cells
+    (ev,) = named(events, "repro.bucket_dispatch")
+    assert ev[3]["grad"] == layout and ev[3]["width"] == 4
     assert drain_timings() == []
 
 
